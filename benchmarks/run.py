@@ -11,7 +11,6 @@ used to track the fused-kernel perf trajectory across PRs.
 from __future__ import annotations
 
 import argparse
-import sys
 import time
 
 BENCHES = ["table2", "table7", "table8", "table345", "fig4", "appA2", "qspsa",
@@ -58,10 +57,7 @@ def main() -> None:
     if "roofline" in only:
         from benchmarks import roofline
 
-        try:
-            roofline.run()
-        except Exception as e:  # dry-run results not generated yet
-            print(f"# roofline skipped: {e}", file=sys.stderr)
+        roofline.run()
     print(f"# benchmarks done in {time.time() - t0:.1f}s")
 
 

@@ -550,9 +550,12 @@ def sharded_forward_rows(iters: int) -> list[dict]:
 
 def _sharded_leg_subprocess(iters: int) -> list[dict]:
     """Run the sharded leg in a child with 8 fake host devices (this process
-    must keep seeing exactly one device — assignment §0)."""
+    must keep seeing exactly one device).  The child is a CPU leg: on a
+    TPU host this process already holds the chip, so the child is kept
+    off it with JAX_PLATFORMS=cpu (XLA_FLAGS host devices alone do not)."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     repo = Path(__file__).resolve().parents[1]
     env["PYTHONPATH"] = str(repo / "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""

@@ -74,7 +74,7 @@ run it replicated, exactly the parameter-sized HBM traffic the kernels
 exist to remove).  When the step builder registers a mesh + per-leaf
 ``PartitionSpec`` table (:func:`shard_context`, threaded from
 ``zo_step.build_zo_train_step``), every kernel-path leaf op instead wraps
-its ops call in ``jax.experimental.shard_map``: each device runs the fused
+its ops call in ``jax.shard_map``: each device runs the fused
 kernel on its **local** shard (local-shape pad-and-mask tiling), factor /
 moment operands ride the specs that ``distributed.sharding.
 mstate_shardings`` assigns (u inherits W's row sharding, v the column
@@ -265,9 +265,9 @@ def _global_offsets(mesh: Mesh, spec: P, local_shape: tuple) -> jax.Array:
 def _shard_call(fn, mesh: Mesh, in_specs, out_specs, *args):
     """shard_map(fn) with replication checking off (pallas_call has no
     replication rule; out-spec correctness is locked by the parity tests)."""
-    from repro.distributed.context import compat_shard_map
-
-    return compat_shard_map(fn, mesh, in_specs=in_specs, out_specs=out_specs)(*args)
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )(*args)
 
 
 def _factor_specs(spec: P) -> tuple[P, P, P]:
@@ -1516,9 +1516,10 @@ def quant_matmul_fwd(x: jax.Array, w: QuantLeaf, *, mode: str = "auto") -> jax.A
 
     No shard_map wrap: the call sites sit under the model's ``lax.scan``
     with per-layer (unbatched) leaves; a tensor-parallel sharded quant
-    forward on a real mesh is an open-item-1 follow-on (GSPMD replicates
-    the pallas_call there — correct, not fast).  Batched leaves always
-    take the twin.
+    forward on a real mesh is a follow-on: a multi-chip jit refuses a
+    Mosaic kernel outside ``shard_map`` ("cannot be automatically
+    partitioned"), so today the kernel path runs on one chip only.
+    Batched leaves always take the twin.
     """
     path, kernel = forward_execution(mode)
     if path == "pallas" and kernel and w.codes.ndim == 2:

@@ -330,7 +330,6 @@ def _build_probe_parallel_step(
             f"{None if mesh is None else mesh.axis_names})"
         )
     from repro.distributed.collectives import probe_assignment
-    from repro.distributed.context import compat_shard_map
     from jax.sharding import PartitionSpec as P
 
     lanes = dict(zip(mesh.axis_names, mesh.devices.shape))["data"]
@@ -412,10 +411,10 @@ def _build_probe_parallel_step(
                     # exact — the other lanes contribute bitwise-neutral 0s
                     return jax.lax.psum(contrib, "data")
 
-            f_mat = compat_shard_map(
-                lane_body, mesh,
+            f_mat = jax.shard_map(
+                lane_body, mesh=mesh,
                 in_specs=(P(), P(), P(), P(), P()),
-                out_specs=P(),
+                out_specs=P(), check_vma=False,
             )(state.params, batch, mstate, key_t, state.step)
 
             # κ and the loss accumulators rebuilt in probe-index order with

@@ -1,12 +1,10 @@
 """Trace-time mesh context: launchers register the mesh so deep model code
 (the shard_map MoE path) can build collectives without threading the mesh
-through every call signature.  Also home of the version-spanning shard_map
-shim used by the MoE path and the shard-aware kernel dispatch."""
+through every call signature."""
 from __future__ import annotations
 
 from typing import Optional
 
-import jax
 from jax.sharding import Mesh
 
 _CURRENT: Optional[Mesh] = None
@@ -20,24 +18,3 @@ def set_current_mesh(mesh: Optional[Mesh]) -> None:
 def current_mesh() -> Optional[Mesh]:
     return _CURRENT
 
-
-def compat_shard_map(f, mesh: Mesh, in_specs, out_specs, check_rep: bool = False):
-    """shard_map across jax versions.
-
-    jax ≥ 0.6 exposes ``jax.shard_map`` (replication checking via
-    ``check_vma``); earlier pins only have ``jax.experimental.shard_map``
-    (``check_rep``).  Checking is off by default here: both call sites wrap
-    ops without replication rules (pallas_call, scatter dispatch), and
-    out-spec correctness is locked by the parity tests instead.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_rep,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_rep,
-    )

@@ -9,7 +9,7 @@ physical page ids).  Insert/evict is then a page-table edit on the host —
 no cache copy ever moves — and one decode step attends each slot's single
 new query against only its own pages.
 
-Grid = (n_slots, KV_heads, pages_per_slot) with the page index innermost
+Grid = (n_slots, pages_per_slot) with the page index innermost
 ("arbitrary" ⇒ sequential on TPU): the block table and per-slot lengths ride
 scalar prefetch (``PrefetchScalarGridSpec``) so the k/v BlockSpec index maps
 chase ``block_table[slot, page]`` — the pool gather IS the DMA schedule, no
@@ -20,8 +20,14 @@ skipped whole via ``@pl.when`` and the partial tail page is masked by
 position.  A slot with length 0 (free slot) contributes nothing and writes
 a zero output tile.
 
-VMEM working set per (slot, kv-head) is tiny — G×dh query + page_size×dh
-k/v + G×page_size f32 scores — decode is bandwidth-bound on the pool reads,
+Each k/v block is one whole page, every (local) kv head of it —
+``(1, page_size, KV, dh)`` — and the kernel loops over kv heads: Mosaic
+accepts a block's second-minor dim only whole or in multiples of 8, so a
+one-head block over KV = 12 is refused.  The pool layout stays
+``[n_pages, page_size, KV, dh]``, one contiguous DMA per page.
+
+VMEM working set per slot is small — KV×G×dh query + page_size×KV×dh k/v +
+KV×G×page_size f32 scores — decode is bandwidth-bound on the pool reads,
 which is the point of paging: only live pages are ever streamed.
 """
 
@@ -34,28 +40,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 NEG_INF = -1e30
 
 
 def _paged_decode_kernel(
     bt_ref,  # scalar prefetch: [S, P] int32 block table
     len_ref,  # scalar prefetch: [S] int32 valid kv length per slot
-    q_ref,  # [1, 1, G, dh]
-    k_ref,  # [1, page_size, 1, dh] — the page picked by the index map
+    q_ref,  # [1, KV, G, dh]
+    k_ref,  # [1, page_size, KV, dh] — the page picked by the index map
     v_ref,
-    o_ref,  # [1, 1, G, dh]
-    m_scr,
+    o_ref,  # [1, KV, G, dh]
+    m_scr,  # [KV, G, 1]
     l_scr,
-    acc_scr,
+    acc_scr,  # [KV, G, dh]
     *,
     page_size: int,
     n_pages: int,
     scale: float,
 ):
     s = pl.program_id(0)
-    ip = pl.program_id(2)
+    ip = pl.program_id(1)
 
     @pl.when(ip == 0)
     def _init():
@@ -68,31 +72,35 @@ def _paged_decode_kernel(
 
     @pl.when(base < length)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32)  # [G, dh]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # [page_size, dh]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        sc = (
-            jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        qa = q_ref[0].astype(jnp.float32)  # [KV, G, dh]
+        # widen the whole page first: Mosaic slices a head out of the
+        # second-minor dim of an f32 value, not of a packed bf16 ref
+        ka = k_ref[0].astype(jnp.float32)  # [page_size, KV, dh]
+        va = v_ref[0].astype(jnp.float32)
+        for h in range(qa.shape[0]):
+            q, k, v = qa[h], ka[:, h, :], va[:, h, :]
+            sc = (
+                jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+                )
+                * scale
+            )  # [G, page_size]
+            kpos = base + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+            sc = jnp.where(kpos < length, sc, NEG_INF)
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+            p = jnp.exp(sc - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[h] = l_scr[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
             )
-            * scale
-        )  # [G, page_size]
-        kpos = base + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        sc = jnp.where(kpos < length, sc, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-        p = jnp.exp(sc - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_scr[...] = m_new
+            m_scr[h] = m_new
 
     @pl.when(ip == n_pages - 1)
     def _fin():
         denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("head_scale", "interpret"))
@@ -123,31 +131,31 @@ def paged_decode_attention(
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S, KV, P),
+        grid=(S, P),
         in_specs=[
-            pl.BlockSpec((1, 1, G, dh), lambda s, h, ip, bt, lens: (s, h, 0, 0)),
+            pl.BlockSpec((1, KV, G, dh), lambda s, ip, bt, lens: (s, 0, 0, 0)),
             pl.BlockSpec(
-                (1, page_size, 1, dh),
-                lambda s, h, ip, bt, lens: (bt[s, ip], 0, h, 0),
+                (1, page_size, KV, dh),
+                lambda s, ip, bt, lens: (bt[s, ip], 0, 0, 0),
             ),
             pl.BlockSpec(
-                (1, page_size, 1, dh),
-                lambda s, h, ip, bt, lens: (bt[s, ip], 0, h, 0),
+                (1, page_size, KV, dh),
+                lambda s, ip, bt, lens: (bt[s, ip], 0, 0, 0),
             ),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, dh), lambda s, h, ip, bt, lens: (s, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, KV, G, dh), lambda s, ip, bt, lens: (s, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, dh), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, dh), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, KV, G, dh), q.dtype),
-        compiler_params=compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(block_tables, lengths, q, k_pages, v_pages)
@@ -156,13 +164,13 @@ def paged_decode_attention(
 def _paged_verify_kernel(
     bt_ref,  # scalar prefetch: [S, P] int32 block table
     len_ref,  # scalar prefetch: [S] int32 kv count valid for window position 0
-    q_ref,  # [1, T, 1, G, dh] — the slot's whole draft window, one kv head
-    k_ref,  # [1, page_size, 1, dh] — the page picked by the index map
+    q_ref,  # [1, T, KV, G, dh] — the slot's whole draft window
+    k_ref,  # [1, page_size, KV, dh] — the page picked by the index map
     v_ref,
-    o_ref,  # [1, T, 1, G, dh]
-    m_scr,
+    o_ref,  # [1, T, KV, G, dh]
+    m_scr,  # [KV, T·G, 1]
     l_scr,
-    acc_scr,
+    acc_scr,  # [KV, T·G, dh]
     *,
     page_size: int,
     n_pages: int,
@@ -175,12 +183,12 @@ def _paged_verify_kernel(
     causal intra-window mask over the draft tokens themselves (whose KV the
     engine has already written into the pages at positions
     ``lengths[s]-1 .. lengths[s]+T-2``).  Collapses the window into the
-    sublane axis ([T·G, dh] queries) so the per-page online-softmax update
-    is one dot + one masked exp, exactly the decode kernel's — at T=1 the
-    arithmetic is instruction-for-instruction the decode kernel's, which
-    the parity tests assert bitwise."""
+    sublane axis ([T·G, dh] queries per kv head) so the per-page
+    online-softmax update is one dot + one masked exp, exactly the decode
+    kernel's — at T=1 the arithmetic is instruction-for-instruction the
+    decode kernel's, which the parity tests assert bitwise."""
     s = pl.program_id(0)
-    ip = pl.program_id(2)
+    ip = pl.program_id(1)
 
     @pl.when(ip == 0)
     def _init():
@@ -198,36 +206,40 @@ def _paged_verify_kernel(
     # live row's running max is finite from the first page on).
     @pl.when((length > 0) & (base < length + n_draft - 1))
     def _body():
-        dh = q_ref.shape[-1]
-        q = q_ref[0, :, 0].astype(jnp.float32)  # [T, G, dh]
-        q = q.reshape(n_draft * group, dh)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # [page_size, dh]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        sc = (
-            jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        qa = q_ref[0].astype(jnp.float32)  # [T, KV, G, dh]
+        ka = k_ref[0].astype(jnp.float32)  # [page_size, KV, dh]
+        va = v_ref[0].astype(jnp.float32)
+        dh = qa.shape[-1]
+        for h in range(qa.shape[1]):
+            q = qa[:, h].reshape(n_draft * group, dh)
+            k, v = ka[:, h, :], va[:, h, :]
+            sc = (
+                jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+                )
+                * scale
+            )  # [T*G, page_size]
+            kpos = base + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+            qt = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 0) // group
+            sc = jnp.where(kpos < length + qt, sc, NEG_INF)
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+            p = jnp.exp(sc - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[h] = l_scr[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
             )
-            * scale
-        )  # [T*G, page_size]
-        kpos = base + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        qt = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 0) // group
-        sc = jnp.where(kpos < length + qt, sc, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-        p = jnp.exp(sc - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_scr[...] = m_new
+            m_scr[h] = m_new
 
     @pl.when(ip == n_pages - 1)
     def _fin():
-        dh = o_ref.shape[-1]
+        kv, dh = o_ref.shape[2], o_ref.shape[-1]
         denom = jnp.maximum(l_scr[...], 1e-30)
-        o = (acc_scr[...] / denom).astype(o_ref.dtype)
-        o_ref[0, :, 0] = o.reshape(n_draft, group, dh)
+        o = (acc_scr[...] / denom).astype(o_ref.dtype)  # [KV, T*G, dh]
+        o_ref[0] = jnp.stack(
+            [o[h].reshape(n_draft, group, dh) for h in range(kv)], axis=1
+        )
 
 
 @functools.partial(jax.jit, static_argnames=("head_scale", "interpret"))
@@ -242,7 +254,7 @@ def paged_verify_attention(
     interpret: bool = False,
 ) -> jax.Array:
     """Returns [S, T, KV, G, dh].  Same scalar-prefetch block-table grid as
-    :func:`paged_decode_attention` — grid (S, KV, P), page index innermost,
+    :func:`paged_decode_attention` — grid (S, P), page index innermost,
     the pool gather IS the DMA schedule — with the whole T-token draft
     window riding the query tile and a causal intra-window mask on top of
     the per-slot length mask.  ``lengths[s]`` counts the kv positions the
@@ -263,35 +275,33 @@ def paged_verify_attention(
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S, KV, P),
+        grid=(S, P),
         in_specs=[
+            pl.BlockSpec((1, T, KV, G, dh), lambda s, ip, bt, lens: (s, 0, 0, 0, 0)),
             pl.BlockSpec(
-                (1, T, 1, G, dh), lambda s, h, ip, bt, lens: (s, 0, h, 0, 0)
+                (1, page_size, KV, dh),
+                lambda s, ip, bt, lens: (bt[s, ip], 0, 0, 0),
             ),
             pl.BlockSpec(
-                (1, page_size, 1, dh),
-                lambda s, h, ip, bt, lens: (bt[s, ip], 0, h, 0),
-            ),
-            pl.BlockSpec(
-                (1, page_size, 1, dh),
-                lambda s, h, ip, bt, lens: (bt[s, ip], 0, h, 0),
+                (1, page_size, KV, dh),
+                lambda s, ip, bt, lens: (bt[s, ip], 0, 0, 0),
             ),
         ],
         out_specs=pl.BlockSpec(
-            (1, T, 1, G, dh), lambda s, h, ip, bt, lens: (s, 0, h, 0, 0)
+            (1, T, KV, G, dh), lambda s, ip, bt, lens: (s, 0, 0, 0, 0)
         ),
         scratch_shapes=[
-            pltpu.VMEM((T * G, 1), jnp.float32),
-            pltpu.VMEM((T * G, 1), jnp.float32),
-            pltpu.VMEM((T * G, dh), jnp.float32),
+            pltpu.VMEM((KV, T * G, 1), jnp.float32),
+            pltpu.VMEM((KV, T * G, 1), jnp.float32),
+            pltpu.VMEM((KV, T * G, dh), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, T, KV, G, dh), q.dtype),
-        compiler_params=compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(block_tables, lengths, q, k_pages, v_pages)

@@ -6,15 +6,24 @@ compute hot-spot of the whole system (the dry-run's memory term is dominated
 by materialized S×T score buffers in the XLA path).  Online-softmax tiling
 keeps the score block (bq×bk f32) in VMEM.
 
-Canonical TPU accumulation pattern: grid = (B, H, nq, nk) with the kv-block
+Canonical TPU accumulation pattern: grid = (B, nq, nk) with the kv-block
 index innermost ("arbitrary" dimension semantics ⇒ sequential on TPU);
 running (m, l, acc) live in VMEM scratch across the nk iterations and the
 output tile is written on the last one.  Fully-masked blocks (above the
 causal diagonal / outside the sliding window) still iterate but skip the
 matmuls via @pl.when.
 
-VMEM working set at (bq=512, bk=512, dh=128):
-  q tile 128 KiB (bf16) + k/v tiles 256 KiB + f32 scores 1 MiB + acc 256 KiB.
+Each block carries every (local) head — ``(1, bq, H, dh)`` over
+``[B, S, H, dh]`` — and the kernel loops over heads: Mosaic accepts a
+block's second-minor dim only whole or in multiples of 8, so a one-head
+block over H = 12 is refused, and carrying all heads keeps the model's
+[B, S, H, dh] layout with no HBM transpose.
+
+VMEM working set grows with bq·H (the block's minor dims pad to (16, 128)
+tiles in bf16, (8, 128) in f32), so the default tile is 128: at H = 12,
+dh = 64 the double-buffered q/k/v/o tiles, their f32 copies and the f32
+(m, l, acc) scratch stay inside the 16 MiB scoped-VMEM default; at 512 they
+do not.
 """
 from __future__ import annotations
 
@@ -25,18 +34,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 NEG_INF = -1e30
 
 
 def _flash_kernel(
     q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-    *, bq: int, bk: int, nk: int, scale: float,
+    *, bq: int, bk: int, nk: int, scale: float, group: int,
     causal: bool, window: int, q_offset: int, kv_len: int,
 ):
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
+    iq = pl.program_id(1)
+    ik = pl.program_id(2)
 
     @pl.when(ik == 0)
     def _init():
@@ -67,27 +74,34 @@ def _flash_kernel(
 
     @pl.when(live)
     def _body():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)      # [bq, dh]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # [bk, dh]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                                       # [bq, bk]
-        s = jnp.where(allow, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_scr[...] = m_new
+        # whole [b, H, dh] tiles widen to f32 first: Mosaic slices a head out
+        # of the second-minor dim of an f32 value, not of a packed bf16 ref
+        qa = q_ref[0].astype(jnp.float32)               # [bq, H, dh]
+        ka = k_ref[0].astype(jnp.float32)               # [bk, KV, dh]
+        va = v_ref[0].astype(jnp.float32)
+        for h in range(qa.shape[1]):
+            q, k, v = qa[:, h, :], ka[:, h // group, :], va[:, h // group, :]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * scale                                   # [bq, bk]
+            s = jnp.where(allow, s, NEG_INF)
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[h] = l_scr[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            m_scr[h] = m_new
 
     @pl.when(ik == nk - 1)
     def _fin():
-        denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        denom = jnp.maximum(l_scr[...], 1e-30)          # [H, bq, 1]
+        o = acc_scr[...] / denom                        # [H, bq, dh]
+        o_ref[0] = jnp.stack(
+            [o[h] for h in range(o.shape[0])], axis=1
+        ).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -105,8 +119,8 @@ def flash_attention(
     causal: bool = True,
     window: int = 0,
     q_offset: int = 0,
-    bq: int = 512,
-    bk: int = 512,
+    bq: int = 128,
+    bk: int = 128,
     kv_len: int = 0,
     head_scale: float = 0.0,
     interpret: bool = False,
@@ -126,26 +140,26 @@ def flash_attention(
 
     kernel = functools.partial(
         _flash_kernel,
-        bq=bq, bk=bk, nk=nk, scale=scale,
+        bq=bq, bk=bk, nk=nk, scale=scale, group=G,
         causal=causal, window=window, q_offset=q_offset, kv_len=kv_len,
     )
     return pl.pallas_call(
         kernel,
-        grid=(B, H, nq, nk),
+        grid=(B, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, dh), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, bk, 1, dh), lambda b, h, iq, ik: (b, ik, h // G, 0)),
-            pl.BlockSpec((1, bk, 1, dh), lambda b, h, iq, ik: (b, ik, h // G, 0)),
+            pl.BlockSpec((1, bq, H, dh), lambda b, iq, ik: (b, iq, 0, 0)),
+            pl.BlockSpec((1, bk, KV, dh), lambda b, iq, ik: (b, ik, 0, 0)),
+            pl.BlockSpec((1, bk, KV, dh), lambda b, iq, ik: (b, ik, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, dh), lambda b, h, iq, ik: (b, iq, h, 0)),
+        out_specs=pl.BlockSpec((1, bq, H, dh), lambda b, iq, ik: (b, iq, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S, H, dh), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, dh), jnp.float32),
+            pltpu.VMEM((H, bq, 1), jnp.float32),
+            pltpu.VMEM((H, bq, 1), jnp.float32),
+            pltpu.VMEM((H, bq, dh), jnp.float32),
         ],
-        compiler_params=compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(q, k, v)

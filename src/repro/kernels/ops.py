@@ -552,7 +552,7 @@ def _pad_axis(a, axis: int, target: int):
     return jnp.pad(a, pad)
 
 
-def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0, bq=512, bk=512):
+def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0, bq=128, bk=128):
     """Fused flash attention with pad-and-mask tiling.
 
     Awkward S/T pad to the sublane-aligned tile (padded kv columns masked

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 
 def _scan_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, h0_ref, y_ref, hl_ref,
                  h_scr, *, bs: int, ns: int):
@@ -95,7 +93,7 @@ def selective_scan(
             jax.ShapeDtypeStruct((B, D, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -113,6 +113,10 @@ def threefry2x32(k0, k1, c0, c1):
     return x0, x1
 
 
+def _u24_to_f32(x: jax.Array) -> jax.Array:
+    return x.astype(jnp.int32).astype(jnp.float32)
+
+
 def counter_normal(k0, k1, rows, cols, probe: int) -> jax.Array:
     """N(0,1) f32 draw per (row, col) element via Threefry + Box–Muller.
 
@@ -123,9 +127,11 @@ def counter_normal(k0, k1, rows, cols, probe: int) -> jax.Array:
     """
     c1 = rows | (jnp.uint32(probe) << jnp.uint32(24))
     b0, b1 = threefry2x32(k0, k1, cols, c1)
-    # 24-bit mantissa uniforms in (0, 1): u ∈ [2^-25, 1 - 2^-25]
-    u1 = (b0 >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2.0 ** -24)
-    u2 = (b1 >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2.0 ** -24)
+    # 24-bit mantissa uniforms in (0, 1): u ∈ [2^-25, 1 - 2^-25].  The
+    # shifted words are < 2^24, so going through int32 is exact — and it is
+    # the cast Mosaic lowers (it has no uint32 -> float32 conversion).
+    u1 = _u24_to_f32(b0 >> jnp.uint32(8)) * jnp.float32(2.0 ** -24)
+    u2 = _u24_to_f32(b1 >> jnp.uint32(8)) * jnp.float32(2.0 ** -24)
     u1 = u1 + jnp.float32(2.0 ** -25)
     r = jnp.sqrt(jnp.float32(-2.0) * jnp.log(u1))
     return r * jnp.cos(jnp.float32(2.0 * math.pi) * u2)
@@ -156,13 +162,18 @@ def _tile_coords(bm: int, bn: int, base_ref):
 
 
 def _seed_words(seed_ref):
-    k0 = jax.lax.bitcast_convert_type(seed_ref[0], jnp.uint32)
-    k1 = jax.lax.bitcast_convert_type(seed_ref[1], jnp.uint32)
-    return k0, k1
+    # Mosaic bitcasts vectors only; a same-width int32 -> uint32 convert of
+    # the SMEM scalar keeps the bits (two's-complement wrap), like the
+    # bitcast in _as_i32_seed that put them there.
+    return seed_ref[0, 0].astype(jnp.uint32), seed_ref[0, 1].astype(jnp.uint32)
 
 
 def _as_i32_seed(seed: jax.Array) -> jax.Array:
-    return jax.lax.bitcast_convert_type(seed.astype(jnp.uint32), jnp.int32)
+    # int32[1, 2]: under vmap (stacked leaves) the batched SMEM block is
+    # (squeezed, 1, 2), whose trailing dims equal the array's — the only
+    # block shape Mosaic accepts for so small an array.
+    seed = jax.lax.bitcast_convert_type(seed.astype(jnp.uint32), jnp.int32)
+    return seed.reshape(1, 2)
 
 
 # ---------------------------------------------------------------------------

@@ -1,11 +1,13 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell and
 record memory / cost / collective analysis (EXPERIMENTS.md §Dry-run).
 
-The two lines above MUST stay the first statements in this file — jax locks
-the device count on first init (assignment, MULTI-POD DRY-RUN §0).
+The lines above MUST stay the first statements in this file — jax locks
+the device count and the platform on first init.  The dry-run is a CPU
+tool: its meshes are fake host devices, so it never takes a TPU.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch yi-34b --shape train_4k
@@ -249,8 +251,6 @@ def run_cell(
     # ---- analyses -------------------------------------------------------
     record["memory_analysis"] = _mem_stats(compiled)
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):
-        ca = ca[0]
     record["xla_cost"] = {
         "flops": float(ca.get("flops", -1)),
         "bytes_accessed": float(ca.get("bytes accessed", -1)),

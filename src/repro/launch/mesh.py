@@ -30,8 +30,8 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
-    """Small mesh over however many (fake or real) devices exist — used by
-    sharding unit tests."""
+    """(data, model) mesh over the first data·model devices: the chips of a
+    TPU host, or fake CPU host devices in the sharding unit tests."""
     import jax
     from jax.sharding import Mesh
 

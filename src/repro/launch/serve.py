@@ -70,6 +70,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 
 
@@ -765,6 +766,7 @@ def main() -> None:
         help="max draft tokens proposed per verify step (with --spec-decode)",
     )
     args = ap.parse_args()
+    use_compile_cache()
     if args.spec_decode and not args.engine:
         ap.error(
             "--spec-decode requires --engine: the static-batch "

@@ -8,7 +8,8 @@ straggler simulation, and crash-safe restart.
     PYTHONPATH=src python -m repro.launch.train \
         --arch opt-125m --smoke --method tezo_adam --steps 300
 
-``--mesh host:D,M`` runs sharded on fake host devices (set
+``--mesh host:D,M`` runs sharded over the first D·M devices: the chips of
+a TPU host, or on CPU fake host devices (set
 XLA_FLAGS=--xla_force_host_platform_device_count=N first) — used by the
 multi-device integration tests; default is single-device.
 """
@@ -28,6 +29,7 @@ from repro.checkpoint import Checkpointer
 from repro.configs import get_config, get_smoke_config
 from repro.core import AdaptiveQ, ZOConfig, build_zo_train_step, init_zo_state
 from repro.core import kernel_execution, zo_pass_count
+from repro.core.dispatch import shard_context
 from repro.core.rank import select_ranks
 from repro.data import DataConfig, Prefetcher, batch_at_step
 from repro.distributed import (
@@ -38,6 +40,7 @@ from repro.distributed import (
     replicated_tree,
     zo_state_shardings,
 )
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 from repro.optim import adamw, build_fo_train_step, init_fo_state
 
@@ -148,6 +151,16 @@ def train(
                 mesh, model.logical_axes(), jax.eval_shape(lambda: state)
             )
 
+    # mesh + the per-leaf spec table turn on shard-aware kernel dispatch:
+    # each leaf's fused perturb/update, and each forward kernel, runs under
+    # shard_map on its local shard — a Mosaic kernel has no GSPMD rule, so on
+    # a real multi-chip mesh it must.  Probe-parallel passes an empty spec
+    # table: every leaf is replicated and the leaf ops run their plain
+    # lowerings.
+    param_specs = None
+    if state_sh is not None:
+        param_specs = {} if probe_parallel else param_spec_table(state_sh.params)
+
     if ensemble > 1:
         if probe_parallel:
             raise ValueError("--probe-parallel does not compose with --ensemble")
@@ -159,19 +172,9 @@ def train(
             straggler_mask_fn=sim.mask_fn() if straggler_prob > 0 else None,
         )
     else:
-        # mesh + the per-leaf spec table turn on shard-aware kernel dispatch:
-        # each leaf's fused perturb/update runs under shard_map on its local
-        # shard instead of GSPMD all-gathering around the pallas_call.
-        # Probe-parallel passes an empty spec table: every leaf is
-        # replicated and the leaf ops run their plain lowerings.
         def build_step(cfg_b):
-            if cfg_b.probe_parallel:
-                return build_zo_train_step(
-                    model.loss_fn, cfg_b, mesh=mesh, param_specs={}
-                )
             return build_zo_train_step(
-                model.loss_fn, cfg_b, mesh=mesh,
-                param_specs=param_spec_table(state_sh.params) if state_sh else None,
+                model.loss_fn, cfg_b, mesh=mesh, param_specs=param_specs
             )
 
         step_fn = build_step(zo_cfg)
@@ -208,7 +211,11 @@ def train(
 
     step_fn = jit_step(step_fn)
 
-    eval_fn = jax.jit(model.loss_fn)
+    def eval_loss(params, batch):
+        with shard_context(mesh, param_specs):
+            return model.loss_fn(params, batch)
+
+    eval_fn = jax.jit(eval_loss)
     eval_batch = {k: jnp.asarray(v) for k, v in batch_at_step(data, 999_999_999).items()}
 
     controller = (
@@ -288,6 +295,15 @@ def train(
             zo_cfg.q_probes, restore_mode, probe_lanes=probe_lanes
         ),
         "final_eval_loss": final_eval,
+        # where the final params really live: the most devices one leaf
+        # spans, and how many leaves are split (not replicated) across them
+        "param_devices": max(
+            len(a.sharding.device_set) for a in jax.tree.leaves(state.params)
+        ),
+        "sharded_param_leaves": sum(
+            a.sharding.shard_shape(a.shape) != a.shape
+            for a in jax.tree.leaves(state.params)
+        ),
         "history": history,
         "wall_s": round(time.time() - t_start, 1),
     }
@@ -361,12 +377,15 @@ def main() -> None:
     ap.add_argument("--log-file", default=None)
     ap.add_argument(
         "--mesh", default=None, metavar="host:D,M",
-        help="run the step sharded on a D×M (data, model) host mesh — set "
-        "XLA_FLAGS=--xla_force_host_platform_device_count=N (N ≥ D·M) before "
-        "launch; under --kernel-mode pallas the dispatch is shard-aware "
-        "(shard_map over local shards, mesh-invariant noise streams)",
+        help="run the step sharded on a D×M (data, model) mesh over the "
+        "first D·M devices — the chips of a TPU host, or on CPU fake host "
+        "devices (set XLA_FLAGS=--xla_force_host_platform_device_count=N, "
+        "N ≥ D·M, before launch); under --kernel-mode pallas the dispatch is "
+        "shard-aware (shard_map over local shards, mesh-invariant noise "
+        "streams)",
     )
     args = ap.parse_args()
+    use_compile_cache()
     kwargs = {k.replace("-", "_"): v for k, v in vars(args).items()}
     mesh_arg = kwargs.pop("mesh", None)
     if mesh_arg is not None:

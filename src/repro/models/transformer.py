@@ -200,12 +200,10 @@ class TransformerLM:
             y_l = jax.lax.psum(y_l, "model")                    # combine experts
             return y_l.reshape(Bl, S, D)
 
-        from repro.distributed.context import compat_shard_map
-
         ba_spec = ba if ba else None
-        fn = compat_shard_map(
+        fn = jax.shard_map(
             local_fn,
-            mesh,
+            mesh=mesh,
             in_specs=(
                 P(ba_spec, None, None),
                 P(None, None),
@@ -214,6 +212,7 @@ class TransformerLM:
                 P("model", None, None),
             ),
             out_specs=P(ba_spec, None, None),
+            check_vma=False,
         )
         return fn(h, p["router"], p["we_gate"], p["we_up"], p["we_down"])
 

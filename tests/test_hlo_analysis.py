@@ -13,13 +13,6 @@ def _compile(fn, *specs):
     return jax.jit(fn).lower(*specs).compile()
 
 
-def _xla_cost(compiled) -> dict:
-    """compiled.cost_analysis() returns a per-device list on some jax pins
-    and a bare dict on others; normalize to the dict."""
-    ca = compiled.cost_analysis()
-    return ca[0] if isinstance(ca, list) else ca
-
-
 def test_loopfree_flops_match_xla():
     def f(w, x):
         return jnp.mean(jax.nn.relu(x @ w) ** 2)
@@ -29,7 +22,7 @@ def test_loopfree_flops_match_xla():
         jax.ShapeDtypeStruct((256, 512), jnp.float32),
         jax.ShapeDtypeStruct((128, 256), jnp.float32),
     )
-    xla = _xla_cost(c)
+    xla = c.cost_analysis()
     mine = analyze_hlo(c.as_text(), 1)
     assert abs(mine.flops / max(xla["flops"], 1) - 1.0) < 0.05
     assert 0.5 < mine.bytes_raw / xla["bytes accessed"] < 2.0
