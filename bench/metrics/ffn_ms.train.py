@@ -1,0 +1,8 @@
+"""Device milliseconds per step in the forwards' ``model.ffn`` scope: norm,
+up (and gate), activation, down and residual of every layer, over the 2q
+forwards; see bench/scopes.py."""
+from bench import scopes
+
+
+def read(ctx):
+    return scopes.group_ms(ctx, "ffn")

@@ -112,6 +112,14 @@ from repro.core.estimator import ZOConfig, get_method
 
 RESTORE_MODES = ("inplace", "unchained", "exact")
 
+# Named scopes of the step's phases.  They only add metadata (each
+# instruction's op_name) to the compiled step; the benchmark's trace
+# reduction (bench/scopes.py) maps device time to them by these names.
+SCOPE_BEGIN = "zo.begin"      # method.begin_step (SubZO, LOZO-m; TeZO: none)
+SCOPE_PERTURB = "zo.perturb"  # the +rho perturb (first, bridge, catch-up)
+SCOPE_FLIP = "zo.flip"        # the -2 rho perturb (the -rho branch in "exact")
+SCOPE_UPDATE = "zo.update"    # the restore and the optimizer update
+
 
 def zo_pass_count(
     q_probes: int, restore_mode: str = "inplace",
@@ -220,7 +228,8 @@ def build_zo_train_step(
     def step_fn(state: ZOTrainState, batch: Any) -> tuple[ZOTrainState, dict]:
         with dispatch.shard_context(mesh, param_specs):
             key_t = jax.random.fold_in(state.base_key, state.step)
-            mstate = method.begin_step(state.mstate, key_t, state.step, cfg)
+            with jax.named_scope(SCOPE_BEGIN):
+                mstate = method.begin_step(state.mstate, key_t, state.step, cfg)
             lr = cfg.schedule(state.step)
 
             params = state.params
@@ -233,46 +242,54 @@ def build_zo_train_step(
                 if cfg.restore_mode == "exact":
                     # branch ±ρ copies off the original params (bit-exact
                     # restore, 2× transient memory)
-                    p_plus = method.perturb(params, mstate, key_t, probe, +rho, cfg, state.step)
+                    with jax.named_scope(SCOPE_PERTURB):
+                        p_plus = method.perturb(params, mstate, key_t, probe, +rho, cfg, state.step)
                     f_plus = loss_fn(p_plus, batch)
-                    p_minus = method.perturb(params, mstate, key_t, probe, -rho, cfg, state.step)
+                    with jax.named_scope(SCOPE_FLIP):
+                        p_minus = method.perturb(params, mstate, key_t, probe, -rho, cfg, state.step)
                     f_minus = loss_fn(p_minus, batch)
                 elif cfg.restore_mode == "unchained":
                     # the literal Algorithm-1 in-place schedule: restore and
                     # next-probe perturb are separate full-W passes
-                    p = method.perturb(params, mstate, key_t, probe, +rho, cfg, state.step)
+                    with jax.named_scope(SCOPE_PERTURB):
+                        p = method.perturb(params, mstate, key_t, probe, +rho, cfg, state.step)
                     f_plus = loss_fn(p, batch)
-                    p = method.perturb(p, mstate, key_t, probe, -2.0 * rho, cfg, state.step)
+                    with jax.named_scope(SCOPE_FLIP):
+                        p = method.perturb(p, mstate, key_t, probe, -2.0 * rho, cfg, state.step)
                     f_minus = loss_fn(p, batch)
-                    params = method.perturb(p, mstate, key_t, probe, +rho, cfg, state.step)
+                    with jax.named_scope(SCOPE_UPDATE):
+                        params = method.perturb(p, mstate, key_t, probe, +rho, cfg, state.step)
                 else:  # "inplace": the chained transitions
-                    if probe == 0:
-                        p = method.perturb(p, mstate, key_t, 0, +rho, cfg, state.step)
-                    else:
-                        # bridge: restore probe−1 and perturb probe, one pass
-                        p = method.perturb_pair(
-                            p, mstate, key_t,
-                            probe - 1, +rho, probe, +rho, cfg, state.step,
-                        )
+                    with jax.named_scope(SCOPE_PERTURB):
+                        if probe == 0:
+                            p = method.perturb(p, mstate, key_t, 0, +rho, cfg, state.step)
+                        else:
+                            # bridge: restore probe−1 and perturb probe, one pass
+                            p = method.perturb_pair(
+                                p, mstate, key_t,
+                                probe - 1, +rho, probe, +rho, cfg, state.step,
+                            )
                     f_plus = loss_fn(p, batch)
-                    p = method.perturb(p, mstate, key_t, probe, -2.0 * rho, cfg, state.step)
+                    with jax.named_scope(SCOPE_FLIP):
+                        p = method.perturb(p, mstate, key_t, probe, -2.0 * rho, cfg, state.step)
                     f_minus = loss_fn(p, batch)
                 kappas.append((f_plus - f_minus) / (2.0 * rho))
                 f_plus_acc = f_plus_acc + f_plus
                 f_minus_acc = f_minus_acc + f_minus
 
             kappa_vec = jnp.stack(kappas).astype(jnp.float32)
-            if cfg.restore_mode == "inplace":
-                # restore_into_update: the last probe's +ρZ restore rides the
-                # fused update pass
-                params, mstate = method.update(
-                    p, mstate, key_t, kappa_vec, lr, cfg, state.step,
-                    restore_probe=cfg.q_probes - 1, restore_scale=+rho,
-                )
-            else:
-                params, mstate = method.update(
-                    params, mstate, key_t, kappa_vec, lr, cfg, state.step
-                )
+            with jax.named_scope(SCOPE_UPDATE):
+                if cfg.restore_mode == "inplace":
+                    # restore_into_update: the last probe's +ρZ restore rides
+                    # the fused update pass
+                    params, mstate = method.update(
+                        p, mstate, key_t, kappa_vec, lr, cfg, state.step,
+                        restore_probe=cfg.q_probes - 1, restore_scale=+rho,
+                    )
+                else:
+                    params, mstate = method.update(
+                        params, mstate, key_t, kappa_vec, lr, cfg, state.step
+                    )
 
         new_state = ZOTrainState(
             params=params,
@@ -343,7 +360,8 @@ def _build_probe_parallel_step(
     def step_fn(state: ZOTrainState, batch: Any) -> tuple[ZOTrainState, dict]:
         with dispatch.shard_context(mesh, param_specs):
             key_t = jax.random.fold_in(state.base_key, state.step)
-            mstate = method.begin_step(state.mstate, key_t, state.step, cfg)
+            with jax.named_scope(SCOPE_BEGIN):
+                mstate = method.begin_step(state.mstate, key_t, state.step, cfg)
             lr = cfg.schedule(state.step)
 
             def lane_body(params_r, batch_r, mstate_r, key_r, step_r):
@@ -360,38 +378,43 @@ def _build_probe_parallel_step(
                             if count == 0:
                                 # more lanes than probes: idle contributor
                                 return out
-                            if start == 0:
-                                p = method.perturb(
-                                    params_r, mstate_r, key_r, 0, +rho,
-                                    cfg, step_r,
-                                )
-                            else:
-                                # catch-up: replay probes 0..start−1's ±ρ
-                                # triples and open probe `start`, one pass
-                                chain_p = tuple(
-                                    j for i in range(start) for j in (i, i, i)
-                                ) + (start,)
-                                chain_s = tuple(
-                                    s for _ in range(start)
-                                    for s in (+rho, -2.0 * rho, +rho)
-                                ) + (+rho,)
-                                p = method.perturb_chain(
-                                    params_r, mstate_r, key_r,
-                                    chain_p, chain_s, cfg, step_r,
-                                )
+                            with jax.named_scope(SCOPE_PERTURB):
+                                if start == 0:
+                                    p = method.perturb(
+                                        params_r, mstate_r, key_r, 0, +rho,
+                                        cfg, step_r,
+                                    )
+                                else:
+                                    # catch-up: replay probes 0..start−1's
+                                    # ±ρ triples and open probe `start`, one
+                                    # pass
+                                    chain_p = tuple(
+                                        j for i in range(start)
+                                        for j in (i, i, i)
+                                    ) + (start,)
+                                    chain_s = tuple(
+                                        s for _ in range(start)
+                                        for s in (+rho, -2.0 * rho, +rho)
+                                    ) + (+rho,)
+                                    p = method.perturb_chain(
+                                        params_r, mstate_r, key_r,
+                                        chain_p, chain_s, cfg, step_r,
+                                    )
                             for j in range(count):
                                 probe = start + j
                                 if j > 0:
-                                    p = method.perturb_pair(
-                                        p, mstate_r, key_r,
-                                        probe - 1, +rho, probe, +rho,
-                                        cfg, step_r,
-                                    )
+                                    with jax.named_scope(SCOPE_PERTURB):
+                                        p = method.perturb_pair(
+                                            p, mstate_r, key_r,
+                                            probe - 1, +rho, probe, +rho,
+                                            cfg, step_r,
+                                        )
                                 f_plus = loss_fn(p, batch_r)
-                                p = method.perturb(
-                                    p, mstate_r, key_r, probe, -2.0 * rho,
-                                    cfg, step_r,
-                                )
+                                with jax.named_scope(SCOPE_FLIP):
+                                    p = method.perturb(
+                                        p, mstate_r, key_r, probe,
+                                        -2.0 * rho, cfg, step_r,
+                                    )
                                 f_minus = loss_fn(p, batch_r)
                                 out = out.at[probe, 0].set(
                                     f_plus.astype(jnp.float32)
@@ -438,10 +461,12 @@ def _build_probe_parallel_step(
             restore_scales = tuple(
                 s for _ in range(q) for s in (+rho, -2.0 * rho, +rho)
             )
-            params, mstate = method.update(
-                state.params, mstate, key_t, kappa_vec, lr, cfg, state.step,
-                restore_probe=restore_probes, restore_scale=restore_scales,
-            )
+            with jax.named_scope(SCOPE_UPDATE):
+                params, mstate = method.update(
+                    state.params, mstate, key_t, kappa_vec, lr, cfg,
+                    state.step, restore_probe=restore_probes,
+                    restore_scale=restore_scales,
+                )
 
         new_state = ZOTrainState(
             params=params,

@@ -20,6 +20,15 @@ from repro.models import layers
 from repro.models.spec import PSpec
 
 
+# Named scopes of the forward's parts.  They only add metadata (each
+# instruction's op_name) to the compiled program; the benchmark's trace
+# reduction (bench/scopes.py) maps device time to them by these names.
+SCOPE_EMBED = "model.embed"  # input embedding and rotary angles
+SCOPE_ATTN = "model.attn"    # norm, q/k/v, attention, o-projection, residual
+SCOPE_FFN = "model.ffn"      # norm, up/gate, activation, down, residual
+SCOPE_HEAD = "model.head"    # final norm, lm_head logits, cross-entropy
+
+
 def _dtype(cfg: ModelConfig):
     return jnp.dtype(cfg.dtype)
 
@@ -261,9 +270,11 @@ class TransformerLM:
         return out.reshape(B, S, D)
 
     def _block(self, p, x, sin, cos, q_offset):
-        o, kv = self._attn(p, x, sin, cos, q_offset)
-        x = x + o
-        x = x + self._ffn(p, x)
+        with jax.named_scope(SCOPE_ATTN):
+            o, kv = self._attn(p, x, sin, cos, q_offset)
+            x = x + o
+        with jax.named_scope(SCOPE_FFN):
+            x = x + self._ffn(p, x)
         return x, kv
 
     # ------------------------------------------------------------------
@@ -278,33 +289,36 @@ class TransformerLM:
 
     def hidden_states(self, params, batch, collect_kv: bool = False):
         c = self.cfg
-        x = self._embed_inputs(params, batch)
-        B, S, D = x.shape
-        positions = jnp.arange(S)
-        sin, cos = layers.rope_angles(positions, c.head_dim, c.rope_theta)
-        sin, cos = sin[None], cos[None]  # [1, S, dh/2]
+        with jax.named_scope(SCOPE_EMBED):
+            x = self._embed_inputs(params, batch)
+            B, S, D = x.shape
+            positions = jnp.arange(S)
+            sin, cos = layers.rope_angles(positions, c.head_dim, c.rope_theta)
+            sin, cos = sin[None], cos[None]  # [1, S, dh/2]
 
         def body(carry, p):
             y, kv = self._block(p, carry, sin, cos, 0)
             return y, (kv if collect_kv else None)
 
         x, kvs = jax.lax.scan(body, x, params["blocks"])
-        x = layers.rms_norm(x, params["final_norm"], c.norm_eps)
+        with jax.named_scope(SCOPE_HEAD):
+            x = layers.rms_norm(x, params["final_norm"], c.norm_eps)
         return x, kvs
 
     def loss_fn(self, params, batch) -> jax.Array:
         c = self.cfg
         x, _ = self.hidden_states(params, batch)
-        P = 0 if batch.get("embeds") is None else batch["embeds"].shape[1]
-        x_tok = x[:, P:, :]
-        targets = batch["targets"]
-        mask = batch.get("mask")
-        if c.logits_chunk > 0:
-            return layers.chunked_cross_entropy(
-                x_tok, params["lm_head"], targets, mask, c.logits_chunk
-            )
-        logits = x_tok @ params["lm_head"]
-        return layers.cross_entropy(logits, targets, mask)
+        with jax.named_scope(SCOPE_HEAD):
+            P = 0 if batch.get("embeds") is None else batch["embeds"].shape[1]
+            x_tok = x[:, P:, :]
+            targets = batch["targets"]
+            mask = batch.get("mask")
+            if c.logits_chunk > 0:
+                return layers.chunked_cross_entropy(
+                    x_tok, params["lm_head"], targets, mask, c.logits_chunk
+                )
+            logits = x_tok @ params["lm_head"]
+            return layers.cross_entropy(logits, targets, mask)
 
     # ------------------------------------------------------------------
     # serving: prefill + single-token decode against a KV cache
