@@ -4,10 +4,13 @@
 interpret mode (the kernel body executed in Python — correctness path); on
 TPU they compile to Mosaic.  Wrappers also handle rank padding (r → multiple
 of 128 for MXU lane alignment, zero-padded so the math is unchanged),
-batched leaves via vmap, and awkward (m, n): dims that don't divide the
-preferred tile are zero-padded up to the tile multiple and the tail sliced
-off after the call — so prime-ish dims (e.g. a 50257-row vocab embedding)
-still get full-width tiles instead of degrading to tiny divisors.
+batched leaves via vmap, and awkward (m, n).  The two TeZO pass kernels
+(``tezo_perturb``, ``tezo_adam_update``, and LOZO through them) take their
+block from ``tezo_tiles`` and cover a dim the block does not divide with a
+partial last block, so a 50272-row vocabulary leaf is never copied.  The
+other weight-leaf kernels zero-pad such a dim up to the tile multiple and
+slice the tail off after the call — so prime-ish dims still get full-width
+tiles instead of degrading to tiny divisors.
 
 These wrappers are the *production* hot path for every ZO method: the
 estimator routes all perturb/update leaf math through ``repro.core.dispatch``,
@@ -34,8 +37,8 @@ traffic on the merged passes.
 Leaves too small/oddly shaped for tiles (biases, norm scales: ndim < 2 or a
 dim < 8) always stay on the dense jnp path — see dispatch's eligibility
 predicates.  ``input_output_aliases`` inside the kernels keeps the three
-Algorithm-1 perturbation passes in-place in HBM (for padded leaves the pad
-copy breaks aliasing; aligned leaves — the common case — stay in-place).
+Algorithm-1 perturbation passes in-place in HBM (for the padded leaves of
+the noise, SubZO and quant kernels the pad copy breaks aliasing).
 
 Sharded dispatch hooks: the noise wrappers take ``offsets`` — the global
 coordinates of this array's origin when it is one device's shard of a
@@ -59,6 +62,7 @@ import jax.numpy as jnp
 from repro.kernels import zo_noise
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.tezo_adam import tezo_adam_update as _adam
+from repro.kernels.tezo_perturb import VMEM_BUDGET as TEZO_VMEM_BUDGET
 from repro.kernels.tezo_perturb import tezo_perturb as _perturb
 from repro.kernels.zo_noise import leaf_seed  # re-export for dispatch
 
@@ -215,6 +219,68 @@ def _crop(out, m: int, n: int):
 # TeZO family
 # ---------------------------------------------------------------------------
 
+# f32 [bm, bn] tiles each kernel body keeps in VMEM, as the v5e compiler
+# allocates them: the f32 W and the reconstruction; the Adam body adds M, V.
+_TEZO_F32_TILES = {"perturb": 2, "adam": 4}
+# the block widths the rule tries (lane multiples)
+_TEZO_BN = (256, 512, 1024)
+# v5e HBM bytes per second, and the fixed cost of one grid step
+_HBM_BPS, _STEP_S = 819e9, 0.35e-6
+
+
+def _tezo_working_set(bm, bn, r_pad, k, kernel, w_bytes):
+    """VMEM bytes of one grid step: the W block in and out and the u/v
+    blocks, each double-buffered, the body's f32 tiles, and one scaled u
+    block per delta (the Adam body adds u·diag(τ_M) and u²·diag(τ_V))."""
+    factor_blocks = k + (2 if kernel == "adam" else 0)
+    return (
+        4 * bm * bn * w_bytes + 8 * (bm + bn) * r_pad
+        + 4 * _TEZO_F32_TILES[kernel] * bm * bn + 4 * factor_blocks * bm * r_pad
+    )
+
+
+def _even_block(dim: int, cap: int, mult: int) -> int:
+    """The ``mult``-aligned block that covers ``dim`` in as few steps as
+    ``cap`` allows, evened out so the partial last block is as full as it
+    can be (one block, rounded up, when the dim fits under ``cap``)."""
+    return _round_up(-(-dim // -(-dim // cap)), mult)
+
+
+@functools.lru_cache(maxsize=None)
+def tezo_tiles(
+    m: int, n: int, r_pad: int, k: int, kernel: str, w_bytes: int = 2
+) -> tuple[int, int]:
+    """(bm, bn) for one [m, n] leaf of a TeZO pass kernel.
+
+    ``r_pad`` is the factors' rank as the kernel sees it, ``k`` the number
+    of rank-r deltas chained before the final write (the perturb chain, or
+    the restore rows the Adam pass folds in), ``kernel`` "perturb" or
+    "adam".  For each block width it takes the tallest block whose working
+    set fits ``TEZO_VMEM_BUDGET`` and keeps the one with the least modelled
+    time: steps × (step cost + W in and out + the v block) + the u blocks.
+    The grid runs j innermost, so u is fetched once per row of blocks and
+    v on every step: factor bytes are r_pad/bm of the W bytes moved, and a
+    tall block keeps them small.  A dim the block does not divide gets a
+    partial last block in the kernel, not a padded copy.
+    """
+    best = None
+    for cap in _TEZO_BN:
+        bn = _even_block(n, cap, _LANE)
+        fixed = _tezo_working_set(0, bn, r_pad, k, kernel, w_bytes)
+        per_row = _tezo_working_set(1, bn, r_pad, k, kernel, w_bytes) - fixed
+        bm_cap = (TEZO_VMEM_BUDGET - fixed) // per_row // _SUBLANE * _SUBLANE
+        if bm_cap < _SUBLANE:
+            continue
+        bm = _even_block(m, bm_cap, _SUBLANE)
+        rows, cols = -(-m // bm), -(-n // bn)
+        step_bytes = 2 * bm * bn * w_bytes + 4 * bn * r_pad
+        t = (rows * cols * (_STEP_S + step_bytes / _HBM_BPS)
+             + rows * 4 * bm * r_pad / _HBM_BPS)
+        if best is None or t < best[0]:
+            best = (t, bm, bn)
+    assert best is not None, (m, n, r_pad, k, kernel)
+    return best[1], best[2]
+
 
 def _decay_scalar(decay):
     """Normalize the optional weight-decay factor to a kernel scalar."""
@@ -241,12 +307,13 @@ def tezo_perturb(w, u, v, tau, scale, *, decay=None, pad_rank: bool = True):
     if pad_rank and not _interpret():
         u, v, tau = _pad_rank(u, v, tau)
     m, n = w.shape
-    bm, bn, m_pad, n_pad = _weight_tiles(m, n)
-    out = _perturb(
-        _pad_w(w, m_pad, n_pad), _pad_rows(u, m_pad), _pad_rows(v, n_pad),
-        tau, scale, _decay_scalar(decay), bm=bm, bn=bn, interpret=_interpret(),
+    r = u.shape[-1]
+    k = tau.reshape((-1, r)).shape[0]
+    bm, bn = tezo_tiles(m, n, r, k, "perturb", w.dtype.itemsize)
+    return _perturb(
+        w, u, v, tau, scale, _decay_scalar(decay), bm=bm, bn=bn,
+        interpret=_interpret(),
     )
-    return _crop(out, m, n)
 
 
 def tezo_adam_update(
@@ -273,13 +340,13 @@ def tezo_adam_update(
         else:
             u, v, tau_m, tau_v, tau_r = _pad_rank(u, v, tau_m, tau_v, tau_r)
     m, n = w.shape
-    bm, bn, m_pad, n_pad = _weight_tiles(m, n)
-    out = _adam(
-        _pad_w(w, m_pad, n_pad), _pad_rows(u, m_pad), _pad_rows(v, n_pad),
-        tau_m, tau_v, lr, eps, _decay_scalar(decay), tau_r, restore_scale,
-        bm=bm, bn=bn, interpret=_interpret(),
+    r = u.shape[-1]
+    k = 0 if tau_r is None else tau_r.reshape((-1, r)).shape[0]
+    bm, bn = tezo_tiles(m, n, r, k, "adam", w.dtype.itemsize)
+    return _adam(
+        w, u, v, tau_m, tau_v, lr, eps, _decay_scalar(decay), tau_r,
+        restore_scale, bm=bm, bn=bn, interpret=_interpret(),
     )
-    return _crop(out, m, n)
 
 
 # ---------------------------------------------------------------------------

@@ -19,8 +19,10 @@ to the separate restore pass it replaces; the Adam update then reads the
 restored tile.  ``decay`` (1 − lr·wd) applies to the update only, exactly as
 in the unchained two-pass order of operations.
 
-Tile working set at (bm=256, bn=512, r=128):
-  W tile 256 KiB (bf16) + u/v slices 192 KiB + f32 M,V tiles 1 MiB ≈ 1.5 MiB.
+Tiling as in kernels/tezo_perturb.py: ``ops.tezo_tiles`` sizes the block
+from a VMEM budget that counts this body's extra f32 tiles (M and V),
+the grid is ``(cdiv(m, bm), cdiv(n, bn))`` with a partial last block on
+dims the block does not divide, and W is updated in place.
 """
 from __future__ import annotations
 
@@ -32,6 +34,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import fence
+from repro.kernels.tezo_perturb import VMEM_BUDGET
 
 
 def _adam_body(sc_ref, w_ref, u_ref, v_ref, tm_ref, tv_ref, o_ref, tr_ref,
@@ -141,9 +144,6 @@ def tezo_adam_update(
 ) -> jax.Array:
     m, n = w.shape
     r = u.shape[-1]
-    bm = min(bm, m)
-    bn = min(bn, n)
-    assert m % bm == 0 and n % bn == 0, (m, n, bm, bn)
     k_r = 1 if tau_r is None else tau_r.reshape((-1, r)).shape[0]
     rs = jnp.asarray(restore_scale, jnp.float32).reshape(-1)
     assert rs.shape[0] in (1, k_r), (rs.shape, k_r)
@@ -174,10 +174,11 @@ def tezo_adam_update(
         kernel = functools.partial(_adam_restore_kernel, barrier=interpret)
     return pl.pallas_call(
         kernel,
-        grid=(m // bm, n // bn),
+        grid=(pl.cdiv(m, bm), pl.cdiv(n, bn)),
         in_specs=in_specs,
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((m, n), w.dtype),
         input_output_aliases={1: 0},
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_BUDGET),
         interpret=interpret,
     )(*operands)

@@ -22,11 +22,18 @@ bitwise identical to the unchained one, only the HBM traffic changes.
 delta only — the update touch of a restore-into-update chain; pure
 perturbation deltas never decay.
 
-Tiling: (bm=256, bn=512) bf16 tiles (256 KiB W-tile) + u/v slices
-(bm·r + bn·r) ≤ ~1.5 MiB VMEM at r=128 — comfortably inside the ~16 MiB
-budget, with MXU-aligned dims (bm, bn, r multiples of 128 — ops.py zero-pads
-r).  input_output_aliasing makes the update in-place in HBM (the functional
-JAX view still sees a fresh array).
+Tiling: ``ops.tezo_tiles`` picks (bm, bn) per leaf from a VMEM budget — tall
+blocks of 2.5–5 MiB of bf16 W at opt-13b widths (5120 × 512 on the FFN
+leaves), the grid ``(cdiv(m, bm), cdiv(n, bn))`` with j innermost.  The u block
+(bm, r) is fetched once per row of blocks and the v block (bn, r) once per
+step, so factor bytes are r/bm of the W bytes moved.  A leaf whose dims the
+block does not divide (a 50272-row vocabulary) gets a partial last block
+instead of a padded copy: its out-of-bounds rows and columns are read as
+unspecified values, and each output element reads only its own W element,
+u row and v row, so they feed only output elements that the store drops.
+input_output_aliasing keeps every leaf in place in HBM (the functional JAX
+view still sees a fresh array).  The rank r is lane-aligned by ops.py
+(zero-padded to a multiple of 128 on the chip).
 """
 from __future__ import annotations
 
@@ -81,6 +88,12 @@ def _perturb_kernel(scale_ref, w_ref, u_ref, v_ref, tau_ref, o_ref, *, k, barrie
         wf = o_ref[...].astype(jnp.float32)
 
 
+# VMEM that one grid step of a TeZO pass kernel may hold (ops.tezo_tiles sizes
+# the blocks to it), and the scoped VMEM limit both kernels compile with: a
+# v5e core has 128 MiB, the compiler's default limit is 16 MiB.
+VMEM_BUDGET = 48 << 20
+
+
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
 def tezo_perturb(
     w: jax.Array,       # [m, n]
@@ -96,10 +109,7 @@ def tezo_perturb(
 ) -> jax.Array:
     m, n = w.shape
     r = u.shape[-1]
-    bm = min(bm, m)
-    bn = min(bn, n)
-    assert m % bm == 0 and n % bn == 0, (m, n, bm, bn)
-    grid = (m // bm, n // bn)
+    grid = (pl.cdiv(m, bm), pl.cdiv(n, bn))
     taus = tau.reshape((-1, r))
     k = taus.shape[0]
     scales = jnp.asarray(scale, jnp.float32).reshape(-1)
@@ -127,5 +137,6 @@ def tezo_perturb(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), w.dtype),
         input_output_aliases={1: 0},
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_BUDGET),
         interpret=interpret,
     )(scale_arr, w, u, v, taus)

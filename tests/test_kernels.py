@@ -95,6 +95,107 @@ def test_kernels_batched_leaves():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
+_SCALES = (1e-3, -2e-3)
+
+
+def _tezo_wrapper(kind, w, u, v, taus, tv):
+    """One TeZO pass through the ops wrapper (2-D or stacked leaf)."""
+    if kind == "perturb_k1":
+        return ops.tezo_perturb(w, u, v, taus[..., 0, :], _SCALES[0])
+    if kind == "perturb_k2":
+        return ops.tezo_perturb(w, u, v, taus, jnp.array(_SCALES), decay=0.999)
+    restore = kind == "adam_restore"
+    return ops.tezo_adam_update(
+        w, u, v, taus[..., 0, :], tv, 1e-4, decay=0.999,
+        tau_r=taus[..., 1, :] if restore else None,
+        restore_scale=_SCALES[0] if restore else 0.0,
+    )
+
+
+def _tezo_kernel(kind, w, u, v, taus, tv, *, bm, bn):
+    """The same pass on one 2-D leaf, straight to the kernel at (bm, bn)."""
+    from repro.kernels.tezo_adam import tezo_adam_update as adam
+    from repro.kernels.tezo_perturb import tezo_perturb as perturb
+
+    tiles = dict(bm=bm, bn=bn, interpret=True)
+    if kind == "perturb_k1":
+        return perturb(w, u, v, taus[0], _SCALES[0], 1.0, **tiles)
+    if kind == "perturb_k2":
+        return perturb(w, u, v, taus, jnp.array(_SCALES), 0.999, **tiles)
+    restore = kind == "adam_restore"
+    return adam(w, u, v, taus[0], tv, 1e-4, 1e-5, 0.999,
+                taus[1] if restore else None,
+                _SCALES[0] if restore else 0.0, **tiles)
+
+
+def _tezo_padded(kind, w, u, v, taus, tv):
+    """The path the retiling replaced: the leaf and its factors zero-padded
+    to the noise kernels' tile multiple, the kernel at those tiles, the
+    tail cropped."""
+    m, n = w.shape
+    bm, bn, m_pad, n_pad = ops._weight_tiles(m, n)
+    out = _tezo_kernel(kind, ops._pad_w(w, m_pad, n_pad),
+                       ops._pad_rows(u, m_pad), ops._pad_rows(v, n_pad),
+                       taus, tv, bm=bm, bn=bn)
+    return ops._crop(out, m, n)
+
+
+@pytest.mark.parametrize("shape", ["clean", "ragged", "stacked"])
+@pytest.mark.parametrize(
+    "kind", ["perturb_k1", "perturb_k2", "adam", "adam_restore"])
+def test_tezo_tiles_match_padded_path(kind, shape):
+    """The TeZO pass kernels on their own blocks — a partial block where
+    the block does not divide the leaf — write the same bits as the padded
+    copy they replace.  Each case also runs the kernel on blocks that
+    divide neither dim of the leaf (partial edge blocks on both axes),
+    against the same padded path."""
+    # the leaf, and edge blocks that divide neither of its dims
+    (m, n), (ebm, ebn) = {"clean": ((256, 512), (48, 384)),
+                          "ragged": ((200, 300), (48, 128)),
+                          "stacked": ((128, 256), (48, 384))}[shape]
+    r = 24
+    lead = (2,) if shape == "stacked" else ()
+    key = jax.random.PRNGKey(m + n)
+    w = (jax.random.normal(key, lead + (m, n)) * 0.1).astype(jnp.bfloat16)
+    u = jax.random.normal(jax.random.fold_in(key, 1), lead + (m, r))
+    v = jax.random.normal(jax.random.fold_in(key, 2), lead + (n, r))
+    taus = jax.random.normal(jax.random.fold_in(key, 3), lead + (2, r))
+    tv = jnp.abs(jax.random.normal(jax.random.fold_in(key, 4), lead + (r,)))
+    got = _tezo_wrapper(kind, w, u, v, taus, tv)
+    if lead:
+        slices = [(w[i], u[i], v[i], taus[i], tv[i]) for i in range(lead[0])]
+    else:
+        slices = [(w, u, v, taus, tv)]
+    want = jnp.stack([_tezo_padded(kind, *a) for a in slices])
+    edge = jnp.stack([_tezo_kernel(kind, *a, bm=ebm, bn=ebn) for a in slices])
+    assert got.shape == w.shape
+    want, edge = want.reshape(w.shape), edge.reshape(w.shape)
+    bits = lambda a: np.asarray(a.view(jnp.uint16))
+    np.testing.assert_array_equal(bits(got), bits(want))
+    np.testing.assert_array_equal(bits(edge), bits(want))
+
+
+@pytest.mark.parametrize("kernel", ["perturb", "adam"])
+@pytest.mark.parametrize("r_pad", [128, 256])
+@pytest.mark.parametrize("m,n", [(5120, 5120), (5120, 20480), (20480, 5120),
+                                 (50272, 5120), (5120, 50272)])
+def test_tezo_tiles_at_opt13b_widths(m, n, r_pad, kernel):
+    """The block rule, a pure function of what the call observes, gives
+    every opt-13b leaf at least 1 MiB of bf16 W per grid step, fetches at
+    most 1/8 of the W bytes moved again as factors (the v block on every
+    step: r_pad/bm), and fits its working set in the VMEM budget."""
+    k = 1
+    bm, bn = ops.tezo_tiles(m, n, r_pad, k, kernel, 2)
+    ops.tezo_tiles.cache_clear()
+    assert ops.tezo_tiles(m, n, r_pad, k, kernel, 2) == (bm, bn)
+    assert bm % 16 == 0 and bn % 128 == 0
+    w_bytes_moved = 2 * bm * bn * 2
+    assert bm * bn * 2 >= 1 << 20
+    assert 4 * bn * r_pad <= w_bytes_moved / 8
+    assert ops._tezo_working_set(bm, bn, r_pad, k, kernel, 2) <= (
+        ops.TEZO_VMEM_BUDGET)
+
+
 FLASH_CASES = [
     # B, S, T, H, KV, dh, window, q_offset
     (2, 128, 128, 4, 2, 32, 0, 0),
