@@ -63,7 +63,7 @@ replays the whole 3q-delta trajectory ((i,+ρ),(i,−2ρ),(i,+ρ) for i=0..q−1
 Because every delta round-trips through the weight dtype exactly as its own
 pass would, regrouping the same delta sequence into different passes is
 bitwise-invariant — the probe-parallel step matches the sequential chained
-step bit for bit (locked by tests/test_sharded_dispatch.py).
+step bit for bit (locked by tests/test_probe_parallel_{tezo,mezo,lozo}.py).
 
 Per-replica pass count: ``zo_pass_count(q, "inplace", probe_lanes=D)`` =
 ``2·ceil(q/D) + 1`` (catch-up/first-perturb + per-probe flip and bridge +

@@ -17,11 +17,7 @@ The real-model tests share one module-scoped engine: serve() must leave the
 scheduler drained and the cache reusable, so running the solo oracles on the
 *same* engine that just served the mixed trace is itself part of the test.
 """
-import os
-import subprocess
-import sys
 import textwrap
-from pathlib import Path
 
 import jax
 import numpy as np
@@ -36,6 +32,8 @@ from repro.launch.serve import (
     prompt_lookup_draft,
 )
 from repro.models import build_model
+
+from _fake_mesh import run_script
 
 pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
 
@@ -503,8 +501,6 @@ def test_spec_eos_and_truncation(spec_engine, engine, cfg, mixed):
 
 _SCRIPT = textwrap.dedent(
     """
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import numpy as np
     import jax
     from repro.configs import get_smoke_config
@@ -555,17 +551,4 @@ _SCRIPT = textwrap.dedent(
 
 @pytest.mark.slow
 def test_engine_staggered_8_fake_devices(tmp_path):
-    script = tmp_path / "engine_8dev.py"
-    script.write_text(_SCRIPT)
-    env = dict(os.environ)
-    repo = Path(__file__).resolve().parents[1]
-    env["PYTHONPATH"] = str(repo / "src")
-    proc = subprocess.run(
-        [sys.executable, str(script)],
-        env=env,
-        capture_output=True,
-        text=True,
-        timeout=900,
-    )
-    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
-    assert "ENGINE_8DEV_OK" in proc.stdout, proc.stdout[-2000:]
+    run_script(tmp_path, "engine_8dev.py", _SCRIPT, ("ENGINE_8DEV_OK",), timeout=60)

@@ -1,17 +1,14 @@
-"""Multi-device SPMD tests — run in a subprocess with 8 fake host devices so
-the rest of the suite keeps seeing exactly 1 device (assignment §0)."""
-import os
-import subprocess
-import sys
+"""Multi-device SPMD tests — run in a subprocess with 8 fake host devices
+(tests/_fake_mesh.py) so the rest of the suite keeps seeing exactly 1 device
+(assignment §0)."""
 import textwrap
-from pathlib import Path
 
 import pytest
 
+from _fake_mesh import run_script
+
 _SCRIPT = textwrap.dedent(
     """
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import numpy as np
     import jax, jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -122,17 +119,9 @@ _SCRIPT = textwrap.dedent(
 
 @pytest.mark.slow
 def test_multidevice_spmd_suite(tmp_path):
-    script = tmp_path / "spmd_suite.py"
-    script.write_text(_SCRIPT)
-    env = dict(os.environ)
-    repo = Path(__file__).resolve().parents[1]
-    env["PYTHONPATH"] = str(repo / "src")
-    proc = subprocess.run(
-        [sys.executable, str(script)], env=env, capture_output=True, text=True,
-        timeout=900,
+    run_script(
+        tmp_path, "spmd_suite.py", _SCRIPT,
+        ("SHARDED_STEP_OK", "SHARDED_SERVE_OK", "EP_MOE_OK",
+         "ELASTIC_RESTORE_OK"),
+        timeout=120,
     )
-    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
-    for marker in (
-        "SHARDED_STEP_OK", "SHARDED_SERVE_OK", "EP_MOE_OK", "ELASTIC_RESTORE_OK"
-    ):
-        assert marker in proc.stdout, (marker, proc.stdout[-2000:])
